@@ -20,9 +20,10 @@ Two cache layouts:
   Unallocated table entries point at the null block 0 and sit beyond
   ``cache_len``, so the mask discards them.
 
-``cache_len`` masking supports ragged batches (continuous batching engine).
-``interpret=None`` auto-detects the backend: compiled on TPU, interpreter
-everywhere else (the CPU validation path).
+``cache_len`` masking supports ragged batches (continuous batching engine);
+both kernels take it by scalar prefetch, so it lives in SMEM.
+``interpret=None`` compiles on TPU and interprets elsewhere (the CPU
+validation path); see ``repro.kernels.call_kernel``.
 """
 from __future__ import annotations
 
@@ -34,19 +35,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import call_kernel
+
 f32 = jnp.float32
 NEG_INF = -1e30
 
 
-def resolve_interpret(interpret: bool | None) -> bool:
-    """interpret=None -> interpret mode only off-TPU (compiled on TPU)."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
-
-
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                   acc_ref, *, scale: float, bk: int, n_blocks: int):
+                   acc_ref, *, scale: float, bk: int, n_blocks: int,
+                   kv_heads: int):
     ki = pl.program_id(1)
 
     @pl.when(ki == 0)
@@ -59,7 +56,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     k = k_ref[0].astype(f32)                    # (BK, hd)
     v = v_ref[0].astype(f32)                    # (BK, hdv)
 
-    cache_len = len_ref[0]
+    cache_len = len_ref[pl.program_id(0) // kv_heads]
     # out-of-bounds tail rows (Smax % bk != 0) hold unspecified data —
     # possibly NaN, which 0·NaN would leak through p @ v; zero them.
     vpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (v.shape[0], 1), 0)
@@ -105,32 +102,33 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
     qh = q.reshape(B * Kh, G, hd)
     kh = k_cache.reshape(B * Kh, Smax, hd)
     vh = v_cache.reshape(B * Kh, Smax, hdv)
-    cl = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,)) \
-        if jnp.asarray(cache_len).ndim <= 1 else cache_len
-    cl = jnp.repeat(cl.reshape(B), Kh).reshape(B * Kh, 1)
+    cl = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
 
     kernel = functools.partial(_decode_kernel, scale=scale, bk=bk,
-                               n_blocks=nk)
-    out = pl.pallas_call(
-        kernel,
+                               n_blocks=nk, kv_heads=Kh)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                  # per-slot cache lengths
         grid=(B * Kh, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda h, j: (h, 0)),
-            pl.BlockSpec((1, G, hd), lambda h, j: (h, 0, 0)),
+            pl.BlockSpec((1, G, hd), lambda h, j, ln: (h, 0, 0)),
             # repro: noqa[PAL201] -- masked tail (pos/cache_len guard on k)
-            pl.BlockSpec((1, bk, hd), lambda h, j: (h, j, 0)),
+            pl.BlockSpec((1, bk, hd), lambda h, j, ln: (h, j, 0)),
             # repro: noqa[PAL201] -- masked tail (vpos zeroing guard on v)
-            pl.BlockSpec((1, bk, hdv), lambda h, j: (h, j, 0)),
+            pl.BlockSpec((1, bk, hdv), lambda h, j, ln: (h, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, G, hdv), lambda h, j: (h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * Kh, G, hdv), q.dtype),
+        out_specs=pl.BlockSpec((1, G, hdv), lambda h, j, ln: (h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), f32),
             pltpu.VMEM((G, 1), f32),
             pltpu.VMEM((G, hdv), f32),
         ],
-        interpret=resolve_interpret(interpret),
-    )(cl, qh, kh, vh)
+    )
+    out = call_kernel(
+        lambda interpret: pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B * Kh, G, hdv), q.dtype),
+            interpret=interpret),
+        cl, qh, kh, vh, interpret=interpret)
     return out.reshape(B, H, hdv)
 
 
@@ -224,10 +222,10 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, cache_len, *,
             pltpu.VMEM((G, hdv), f32),
         ],
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * Kh, G, hdv), q.dtype),
-        interpret=resolve_interpret(interpret),
-    )(bt, cl, qh, k_pool, v_pool)
+    out = call_kernel(
+        lambda interpret: pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B * Kh, G, hdv), q.dtype),
+            interpret=interpret),
+        bt, cl, qh, k_pool, v_pool, interpret=interpret)
     return out.reshape(B, H, hdv)

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import call_kernel
+
 f32 = jnp.float32
 NEG_INF = -1e30
 
@@ -70,11 +72,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: float | None = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True,
+                    block_k: int = 128, interpret: bool | None = None,
                     q_offset: int | None = None):
     """q: (B, Sq, H, hd); k/v: (B, Skv, Kh, hd/hdv). Returns (B, Sq, H, hdv).
 
-    interpret=True validates on CPU; on TPU pass interpret=False.
+    interpret=None compiles on TPU and interprets elsewhere
+    (``repro.kernels.call_kernel``).
     q_offset: absolute position of q[:, 0] within the kv span; ``None``
     keeps the legacy END-alignment (q rows are the last Sq of Skv), which
     chunked prefill overrides with the chunk's start offset.
@@ -105,7 +108,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         bq=bq, bk=bk, n_kv=nk, seq_q=Sq, seq_kv=Skv,
         q_offset=(Skv - Sq) if q_offset is None else int(q_offset))
 
-    out = pl.pallas_call(
+    out = call_kernel(lambda interpret: pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
         in_specs=[
@@ -121,6 +124,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, hdv), f32),    # output accumulator
         ],
         interpret=interpret,
-    )(qh, kh, vh)
+    ), qh, kh, vh, interpret=interpret)
     out = out.reshape(B, H, nq * bq, hdv)[:, :, :Sq].transpose(0, 2, 1, 3)
     return out

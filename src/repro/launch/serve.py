@@ -1,26 +1,74 @@
-"""Serving launcher: run the FlexPipe engine on an arch's smoke config with
-a CV-controlled workload and live refactoring.
+"""Serving launcher: run the FlexPipe engine on an arch's smoke config (or,
+with --published, its published widths in bf16) with a CV-controlled
+workload and live refactoring.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
         --rate 10 --cv 4 --duration 5
+
+``build_engine`` is the one place an engine is assembled from a model
+config; ``chip_smoke.py`` builds its engines through it too.
 """
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
+from typing import Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import get_arch
+from repro.configs.base import ModelConfig, get_arch
 from repro.core.controller import FlexPipeController
 from repro.core.granularity import GranularityProfile
 from repro.models.transformer import init_model
 from repro.serving.admission import AdmissionConfig
 from repro.serving.engine import (EngineConfig, FlexPipeEngine,
-                                  KVCacheConfig, PrefillConfig)
+                                  KVCacheConfig, PrefillConfig,
+                                  balanced_boundaries)
 from repro.serving.faults import (FaultInjector, FaultPolicy,
                                   StageHealthMonitor)
 from repro.serving.workload import audit_requests, synth_requests
+
+
+# fixed, inside the checkout: the path is part of the cache key, so a
+# directory that moved between runs would never hit
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is changed; otherwise the cache goes to ``COMPILE_CACHE_DIR``.
+    Call before the first compile."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(COMPILE_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build_engine(cfg: ModelConfig, dtype, *, max_batch: int, max_seq: int,
+                 stages: tuple[int, ...], params: Optional[dict] = None,
+                 **engine_kw) -> FlexPipeEngine:
+    """A FlexPipe engine serving ``cfg`` with weights and KV cache in
+    ``dtype``.
+
+    Weights are ``params`` or, when None, random from seed 0.  The engine
+    starts at ``stages[0]`` balanced stages and precompiles every stage
+    count in ``stages`` (``warm_profiles``), so refactoring among them never
+    traces.  ``engine_kw`` goes to ``EngineConfig``."""
+    if params is None:
+        params = init_model(jax.random.PRNGKey(0), cfg, dtype)
+    ecfg = EngineConfig(max_batch=max_batch, max_seq=max_seq,
+                        cache_dtype=jnp.dtype(dtype).name,
+                        warm_profiles=tuple(stages), **engine_kw)
+    return FlexPipeEngine(cfg, params,
+                          boundaries=balanced_boundaries(cfg.n_layers,
+                                                         stages[0]),
+                          ecfg=ecfg)
 
 
 def main() -> None:
@@ -30,6 +78,9 @@ def main() -> None:
     ap.add_argument("--cv", type=float, default=2.0)
     ap.add_argument("--duration", type=float, default=5.0)
     ap.add_argument("--max-batch", type=int, default=4)
+    ap.add_argument("--published", action="store_true",
+                    help="serve the arch's published widths in bf16 "
+                         "instead of its float32 smoke config")
     # fault injection (0 disables a kind); the schedule is fully determined
     # by --fault-seed, so fault runs are byte-reproducible
     fault = ap.add_argument_group("faults")
@@ -84,8 +135,8 @@ def main() -> None:
     args = ap.parse_args()
 
     spec = get_arch(args.arch)
-    cfg = spec.smoke_config
-    params = init_model(jax.random.PRNGKey(0), cfg)
+    cfg = spec.config if args.published else spec.smoke_config
+    dtype = jnp.bfloat16 if args.published else jnp.float32
     n = cfg.n_layers
     profiles = [
         GranularityProfile(stages=max(n // 4, 1), batch=8, throughput=90,
@@ -101,25 +152,24 @@ def main() -> None:
             edf=not args.no_edf, shed=not args.no_shed,
             brownout=not args.no_brownout,
             kv_high_watermark=args.kv_high, kv_low_watermark=args.kv_low)
-    eng = FlexPipeEngine(cfg, params,
-                         boundaries=[i * 4 for i in range(max(n // 4, 1))],
-                         ecfg=EngineConfig(
-                             max_batch=args.max_batch, max_seq=96,
-                             # precompile every granularity the controller
-                             # can pick: refactors then never stall on XLA
-                             warm_profiles=tuple(p.stages for p in profiles),
-                             # bound post-preemption replay to 8 ticks
-                             snapshot_interval=8,
-                             admission=admission,
-                             kv=KVCacheConfig(
-                                 paged=args.paged,
-                                 block_size=args.block_size,
-                                 n_blocks=args.n_blocks,
-                                 paged_kernel=args.paged_kernel),
-                             prefill=PrefillConfig(
-                                 buckets=not args.no_prefill_buckets,
-                                 chunk=args.prefill_chunk,
-                                 budget=args.prefill_budget)))
+    enable_compile_cache()
+    eng = build_engine(
+        cfg, dtype, max_batch=args.max_batch, max_seq=96,
+        # precompile every granularity the controller can pick: refactors
+        # then never stall on XLA
+        stages=tuple(p.stages for p in profiles),
+        # bound post-preemption replay to 8 ticks
+        snapshot_interval=8,
+        admission=admission,
+        kv=KVCacheConfig(
+            paged=args.paged,
+            block_size=args.block_size,
+            n_blocks=args.n_blocks,
+            paged_kernel=args.paged_kernel),
+        prefill=PrefillConfig(
+            buckets=not args.no_prefill_buckets,
+            chunk=args.prefill_chunk,
+            budget=args.prefill_budget))
     if args.preempt_rate or args.slowdown_rate:
         eng.attach_faults(
             injector=FaultInjector(seed=args.fault_seed,

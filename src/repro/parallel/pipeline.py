@@ -34,17 +34,6 @@ from repro.training.optimizer import AdamWConfig, OptState, adamw_update
 
 f32 = jnp.float32
 
-try:                                    # jax >= 0.6 top-level API
-    _shard_map = jax.shard_map
-except AttributeError:                  # jax 0.4/0.5: experimental home and
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def _shard_map(f, **kw):            # check_vma was spelled check_rep
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_legacy(f, **kw)
-
-
 # ---------------------------------------------------------------------------
 # Param stacking
 # ---------------------------------------------------------------------------
@@ -706,7 +695,7 @@ def build_train_step(cfg: ModelConfig, plan: PipelinePlan, base_mesh: Mesh,
         return new_p, new_o, metrics
 
     mspecs = {"loss": P(), "aux": P(), "grad_norm": P(), "lr": P()}
-    fn = _shard_map(step, mesh=mesh,
+    fn = jax.shard_map(step, mesh=mesh,
                        in_specs=(pspecs, ospecs, bspecs),
                        out_specs=(pspecs, ospecs, mspecs), check_vma=False)
     jitted = jax.jit(
@@ -769,7 +758,7 @@ def build_prefill_step(cfg: ModelConfig, plan: PipelinePlan, base_mesh: Mesh,
         return res["last_logits"], _cache_unsqueeze(res["caches"])
 
     lspec = P(_dp_entry(shape, plan), VP_AXES)
-    fn = _shard_map(step, mesh=mesh, in_specs=(pspecs, bspecs),
+    fn = jax.shard_map(step, mesh=mesh, in_specs=(pspecs, bspecs),
                        out_specs=(lspec, cspecs), check_vma=False)
     jitted = jax.jit(
         fn,
@@ -803,7 +792,7 @@ def build_decode_step(cfg: ModelConfig, plan: PipelinePlan, base_mesh: Mesh,
             fsdp_ctx=fsdp_ctx)
         return logits, _cache_unsqueeze(new_caches)
 
-    fn = _shard_map(step, mesh=mesh,
+    fn = jax.shard_map(step, mesh=mesh,
                        in_specs=(pspecs, cspecs, tok_spec, P()),
                        out_specs=(lspec, cspecs), check_vma=False)
     jitted = jax.jit(

@@ -38,8 +38,9 @@ profiles at engine start so steady-state refactors never trace.
 Continuous batching: fixed slot array; per-slot cache length (ragged decode
 through the position-vector path in models/layers.py).
 
-On this CPU container all stages share one device; on real hardware each
-stage program pins to its own ICI slice (device_put on the stage's devices).
+Placement: every stage program runs on one device (the default one); no
+stage is pinned to chips of its own yet.  Stages on separate chips are
+ROADMAP item R2.
 """
 from __future__ import annotations
 

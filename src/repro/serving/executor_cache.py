@@ -300,17 +300,23 @@ class FusedDecodeProgram:
         self._run_params = run_params
         self._head_params = head_params
 
+    def _args(self, caches, tok, pos, block_tables):
+        tables = () if block_tables is None else (block_tables,)
+        return (self._head_params, list(caches), self._run_params, tok,
+                pos) + tables
+
     def step(self, caches: list, tok, pos, block_tables=None):
         """One tick.  ``caches`` is DONATED — adopt the returned list.
         Paged programs additionally take the (B, max_blocks) block tables."""
-        if block_tables is not None:
-            nxt, new = self._fn(self._head_params, list(caches),
-                                self._run_params, tok, pos, block_tables)
-        else:
-            nxt, new = self._fn(self._head_params, list(caches),
-                                self._run_params, tok, pos)
+        nxt, new = self._fn(*self._args(caches, tok, pos, block_tables))
         self.compiled = True
         return nxt, list(new)
+
+    def lower(self, caches: list, tok, pos, block_tables=None):
+        """Lower the tick without running it.  Arguments are arrays or
+        ShapeDtypeStructs; ``.compile()`` of the result gives the program's
+        ``memory_analysis()`` and its HLO text."""
+        return self._fn.lower(*self._args(caches, tok, pos, block_tables))
 
 
 class ExecutorCache:

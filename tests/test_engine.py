@@ -98,3 +98,65 @@ class TestConsistencyProtocol:
         k = merged[0]["mixer"]["k"]            # (B, Kh, Smax, hd)
         assert float(k[0, 0, 4, 0]) == 1.0     # pre-snapshot token
         assert float(k[0, 0, 5, 0]) == 2.0     # decoded in flight
+
+
+class TestLauncher:
+    def test_build_engine_published_dtype_and_warm_refactor(self):
+        """``build_engine`` (the launcher's and chip smoke's constructor)
+        serves in the dtype it is given, weights and cache alike, starts at
+        the first stage count and warms every one it lists."""
+        from repro.launch.serve import build_engine
+        eng = build_engine(CFG, jnp.bfloat16, max_batch=2, max_seq=64,
+                           stages=(2, 4))
+        assert eng.boundaries == [0, 2]
+        assert {l.dtype for l in jax.tree.leaves(eng.params)} \
+            == {jnp.dtype(jnp.bfloat16)}
+        assert {l.dtype for l in jax.tree.leaves(eng.caches)} \
+            == {jnp.dtype(jnp.bfloat16)}
+        for r in _reqs(n=2, tokens=4):
+            eng.submit(r, now=0.0)
+        eng.step(0.0)
+        ev = eng.refactor([0, 1, 2, 3])
+        assert ev["inflight"] == 2
+        assert ev["compile_cache_hit"] and ev["new_traces"] == 0
+        now = 0.05
+        while any(not s.done for s in eng.slots):
+            eng.step(now)
+            now += 0.05
+        assert eng.stats.completed == 2
+
+    @pytest.mark.parametrize("from_env", [True, False],
+                             ids=["env", "checkout"])
+    def test_compile_cache_placement(self, tmp_path, monkeypatch, from_env):
+        """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; without it the
+        cache goes to a fixed directory inside the checkout that git
+        ignores.  Compiled programs land in the chosen directory."""
+        from jax.experimental.compilation_cache import compilation_cache
+        from repro.launch import serve
+        repo = serve.COMPILE_CACHE_DIR.parent
+        before = jax.config.jax_compilation_cache_dir
+        min_time = jax.config.jax_persistent_cache_min_compile_time_secs
+        try:
+            if from_env:
+                monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+                assert serve.enable_compile_cache() == str(tmp_path)
+                assert jax.config.jax_compilation_cache_dir == before
+                # what JAX does with the variable when it starts
+                jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+                jax.config.update(
+                    "jax_persistent_cache_min_compile_time_secs", 0)
+                compilation_cache.reset_cache()
+                jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+                assert list(tmp_path.iterdir())
+            else:
+                monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR",
+                                   raising=False)
+                path = serve.enable_compile_cache()
+                assert path == str(repo / ".jax_cache")
+                assert jax.config.jax_compilation_cache_dir == path
+                assert ".jax_cache/" in (repo / ".gitignore").read_text()
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+            jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                              min_time)
+            compilation_cache.reset_cache()

@@ -12,8 +12,7 @@ from repro.configs.base import get_arch
 from repro.core.refactoring import (CacheSnapshot, block_validity,
                                     merge_paged_with_mask)
 from repro.kernels.decode_attention import (decode_attention,
-                                            paged_decode_attention,
-                                            resolve_interpret)
+                                            paged_decode_attention)
 from repro.models.kvcache import (BlockAllocator, blocks_for, can_page,
                                   fragmentation, init_paged_cache)
 from repro.models.layers import decode_attention_jnp
@@ -151,10 +150,18 @@ def test_dense_decode_no_pad_tail():
 
 
 def test_resolve_interpret_auto():
-    on_tpu = jax.default_backend() == "tpu"
-    assert resolve_interpret(None) == (not on_tpu)
-    assert resolve_interpret(True) is True
-    assert resolve_interpret(False) is False
+    """interpret=None picks the lowering platform's mode: on the CPU that
+    is the interpreter, so the program carries no Mosaic call and gives
+    exactly what interpret=True gives."""
+    cache_len = np.asarray([5, 40], np.int32)
+    kp, vp, bt = _paged_setup(2, 2, 16, 8, 6, cache_len)
+    q = jax.random.normal(KEY, (2, 4, 16), jnp.float32)
+    args = (q, kp, vp, bt, jnp.asarray(cache_len))
+    auto = jax.jit(paged_decode_attention)
+    assert "tpu_custom_call" not in auto.lower(*args).as_text()
+    np.testing.assert_array_equal(
+        np.asarray(auto(*args)),
+        np.asarray(paged_decode_attention(*args, interpret=True)))
 
 
 # ---------------------------------------------------------------------------

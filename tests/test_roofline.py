@@ -17,12 +17,6 @@ from repro.launch.roofline import (PEAK_FLOPS, hbm_footprint, layer_fwd,
 from repro.models.transformer import BlockCtx, apply_block, init_block
 
 
-def _cost_analysis(compiled) -> dict:
-    """jax>=0.6 returns a dict; 0.4/0.5 a one-element list of dicts."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 @pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "rwkv6-1.6b",
                                   "gemma3-12b"])
 def test_layer_flops_match_xla_probe(arch):
@@ -39,7 +33,7 @@ def test_layer_flops_match_xla_probe(arch):
         return y
 
     compiled = jax.jit(probe).lower(params, x).compile()
-    xla_flops = _cost_analysis(compiled).get("flops", 0.0)
+    xla_flops = compiled.cost_analysis().get("flops", 0.0)
     ana = layer_fwd(cfg, 0, B * S, S, T=1, decode=False).flops
     # probe has no causal-halving (full S x S scores materialized in-scan? no
     # -- flash computes all blocks, masked): analytic uses 0.5 for causal.
@@ -73,7 +67,7 @@ def test_layer_flops_moe_probe_loose():
         return apply_block(cfg, kind, p, x, ctx)[0]
 
     compiled = jax.jit(probe).lower(params, x).compile()
-    xla_flops = _cost_analysis(compiled).get("flops", 0.0)
+    xla_flops = compiled.cost_analysis().get("flops", 0.0)
     ana = layer_fwd(cfg, 0, B * S, S, T=1, decode=False).flops
     assert 0.4 < xla_flops / ana < 3.0
 

@@ -1,0 +1,122 @@
+"""Compile-only checks for a described TPU v5e chip (no chip needed).
+
+The TPU compiler is installed with JAX and compiles for a topology that is
+described and not attached.  This refuses what interpret mode accepts: block
+shapes off the (8, 128) tiling, slices Mosaic cannot lower, programs larger
+than the chip's memory.  Each kernel of the main path is compiled with
+``interpret=False`` at qwen1.5-0.5b's published widths (rwkv6-1.6b's for
+``wkv6``), and so is the fused decode tick.  Nothing runs: no result or
+time comes from these tests.
+
+The topology is described inside a module fixture, never while a module is
+imported: only one process at a time may load the TPU library, and a test
+worker that did so at import would leave the others nothing to collect.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.base import get_arch
+from repro.kernels.decode_attention import (decode_attention,
+                                            paged_decode_attention)
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rwkv6_wkv import wkv6
+from repro.models.kvcache import init_cache, init_paged_cache
+from repro.models.ssm import rwkv_dims
+from repro.models.transformer import init_model
+from repro.serving.engine import balanced_boundaries
+from repro.serving.executor_cache import ExecutorCache
+
+V5E_HBM_BYTES = 16e9
+QWEN = get_arch("qwen1.5-0.5b").config
+B = 8                      # the engine's max_batch on the chip
+SMAX = 2048                # dense cache rows per slot
+BLOCK = 16                 # paged block size
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                 # no TPU compiler to describe it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(tree, sharding):
+    """ShapeDtypeStructs of ``tree`` placed on the described chip."""
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree, is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+
+
+def _kernel_case(name, dt):
+    H, Kh, hd = QWEN.n_heads, QWEN.n_kv_heads, QWEN.resolved_head_dim
+    S = jax.ShapeDtypeStruct
+    i32 = jnp.int32
+    if name == "paged_decode_attention":
+        n_blocks = 1 + B * SMAX // BLOCK
+        return (lambda q, k, v, bt, cl: paged_decode_attention(
+                    q, k, v, bt, cl, interpret=False),
+                [S((B, H, hd), dt), S((n_blocks, Kh, BLOCK, hd), dt),
+                 S((n_blocks, Kh, BLOCK, hd), dt),
+                 S((B, SMAX // BLOCK), i32), S((B,), i32)])
+    if name == "decode_attention":
+        return (lambda q, k, v, cl: decode_attention(q, k, v, cl,
+                                                     interpret=False),
+                [S((B, H, hd), dt), S((B, Kh, SMAX, hd), dt),
+                 S((B, Kh, SMAX, hd), dt), S((B,), i32)])
+    if name == "flash_attention":
+        return (lambda q, k, v: flash_attention(q, k, v, interpret=False),
+                [S((1, 512, H, hd), dt), S((1, 512, Kh, hd), dt),
+                 S((1, 512, Kh, hd), dt)])
+    Hr, hs = rwkv_dims(get_arch("rwkv6-1.6b").config)
+    return (lambda r, k, v, w, u: wkv6(r, k, v, w, u, interpret=False),
+            [S((1, 256, Hr, hs), dt)] * 4 + [S((Hr, hs), dt)])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", ["paged_decode_attention",
+                                  "decode_attention", "flash_attention",
+                                  "wkv6"])
+def test_kernel_compiles_for_v5e(one_chip, name, dtype):
+    fn, args = _kernel_case(name, dtype)
+    compiled = jax.jit(fn).lower(*_on(args, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_fused_tick_fits_v5e(one_chip, paged):
+    """The 6-stage fused decode tick at published widths in bf16 compiles
+    for one v5e and fits its memory.  The paged tick runs the table-walk
+    kernel with the engine's default ``interpret=None``, which must resolve
+    to the Mosaic kernel when the program is built for a TPU."""
+    bf16 = jnp.bfloat16
+    params = _on(jax.eval_shape(
+        lambda: init_model(jax.random.PRNGKey(0), QWEN, bf16)), one_chip)
+    ex = ExecutorCache(QWEN, params, max_batch=B, max_seq=SMAX,
+                       cache_dtype=bf16, paged=paged, paged_kernel=paged)
+    prog, _ = ex.fused_decode(balanced_boundaries(QWEN.n_layers, 6))
+    if paged:
+        caches = init_paged_cache(QWEN, 1 + B * SMAX // BLOCK, BLOCK, bf16,
+                                  materialize=False)
+        tables = jax.ShapeDtypeStruct((B, SMAX // BLOCK), jnp.int32)
+    else:
+        caches = init_cache(QWEN, B, SMAX, bf16, materialize=False)
+        tables = None
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32)
+    compiled = prog.lower(*_on((caches, tok, pos, tables), one_chip)
+                          ).compile()
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert need < V5E_HBM_BYTES, ma
+    assert ("tpu_custom_call" in compiled.as_text()) == paged
