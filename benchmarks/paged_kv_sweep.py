@@ -123,8 +123,7 @@ def bench_concurrency(arch: str, *, hbm_rows: int, max_seq: int,
         "paged_max_concurrent": paged_peak,
         "concurrency_gain": paged_peak / max(dense_peak, 1),
         "paged_preemptions": paged.stats.counters.get("paged_preemptions", 0),
-        "paged_peak_frag": max((g for _, _, _, g in
-                                paged.stats.block_samples), default=0.0),
+        "paged_peak_frag": paged.stats.block_summary()["max_frag"],
     }
 
 

@@ -191,6 +191,10 @@ def main() -> None:
     lat = stats.latency_percentiles()
     print(f"completed={stats.completed} p50={lat['p50']:.2f}s "
           f"p99={lat['p99']:.2f}s refactors={len(eng.refactor_events)}")
+    kv = stats.kv_summary()
+    print(f"kv: decode_ticks={kv['decode_ticks']} "
+          f"mean_live_rows={kv['mean_live_rows']:.1f} "
+          f"live_share={100 * kv['live_share']:.1f}%")
     if eng.admission is not None:
         o = stats.overload_summary()
         counts, violations = audit_requests(reqs)

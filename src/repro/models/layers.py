@@ -222,16 +222,18 @@ def _paged_attention(q, k, v, cache, block_table, *, pos0, wo, kv_block,
     B, S, H, hd = q.shape
     bs = cache["k"].shape[2]
     M = block_table.shape[1]
-    km = jnp.moveaxis(k, 1, 2).astype(cache["k"].dtype)     # (B, Kh, S, hd)
-    vm = jnp.moveaxis(v, 1, 2).astype(cache["v"].dtype)
+    with jax.named_scope("kv_write"):
+        km = jnp.moveaxis(k, 1, 2).astype(cache["k"].dtype)  # (B, Kh, S, hd)
+        vm = jnp.moveaxis(v, 1, 2).astype(cache["v"].dtype)
     bt = jnp.asarray(block_table)
 
     if S == 1:
         p0 = jnp.broadcast_to(jnp.asarray(pos0).reshape(-1), (B,))
-        pid = bt[jnp.arange(B), p0 // bs]
-        off = p0 % bs
-        kc = cache["k"].at[pid, :, off, :].set(km[:, :, 0, :])
-        vc = cache["v"].at[pid, :, off, :].set(vm[:, :, 0, :])
+        with jax.named_scope("kv_write"):
+            pid = bt[jnp.arange(B), p0 // bs]
+            off = p0 % bs
+            kc = cache["k"].at[pid, :, off, :].set(km[:, :, 0, :])
+            vc = cache["v"].at[pid, :, off, :].set(vm[:, :, 0, :])
         if paged_kernel:
             out = paged_decode_attention(q[:, 0], kc, vc, bt,
                                          p0 + 1)[:, None]
@@ -245,10 +247,13 @@ def _paged_attention(q, k, v, cache, block_table, *, pos0, wo, kv_block,
             out = decode_attention_jnp(q, gk, gv, cache_len=p0 + 1)
     else:
         pos = jnp.asarray(pos0).reshape(-1)[:1] + jnp.arange(S)
-        pids = bt[0, pos // bs]
-        offs = pos % bs
-        kc = cache["k"].at[pids, :, offs, :].set(jnp.moveaxis(km[0], 0, 1))
-        vc = cache["v"].at[pids, :, offs, :].set(jnp.moveaxis(vm[0], 0, 1))
+        with jax.named_scope("kv_write"):
+            pids = bt[0, pos // bs]
+            offs = pos % bs
+            kc = cache["k"].at[pids, :, offs, :].set(
+                jnp.moveaxis(km[0], 0, 1))
+            vc = cache["v"].at[pids, :, offs, :].set(
+                jnp.moveaxis(vm[0], 0, 1))
         if kv_extent:
             # chunked prefill: attend over the slot's logical view so this
             # chunk's queries see all previously committed chunks
@@ -313,8 +318,9 @@ def apply_attention(cfg: ModelConfig, params: Params, x: jax.Array, *,
     if use_sp:
         km = jnp.moveaxis(k, 1, 2)
         vm = jnp.moveaxis(v, 1, 2)
-        new_cache = {"k": sp_cache_write(cache["k"], km, pos0, sp_axis),
-                     "v": sp_cache_write(cache["v"], vm, pos0, sp_axis)}
+        with jax.named_scope("kv_write"):
+            new_cache = {"k": sp_cache_write(cache["k"], km, pos0, sp_axis),
+                         "v": sp_cache_write(cache["v"], vm, pos0, sp_axis)}
         out = sp_decode_attention(q, new_cache["k"], new_cache["v"], pos0, sp_axis)
         y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
         y = _maybe_psum(y, tp_axis)
@@ -322,36 +328,46 @@ def apply_attention(cfg: ModelConfig, params: Params, x: jax.Array, *,
 
     new_cache = None
     if cache is not None:
-        Smax = cache["k"].shape[2]
-        km = jnp.moveaxis(k, 1, 2).astype(cache["k"].dtype)   # (B, Kh, S, hd)
-        vm = jnp.moveaxis(v, 1, 2).astype(cache["v"].dtype)
-        pos_vec = jnp.asarray(pos0).ndim == 1
-        if S == 1 and pos_vec:
-            # ragged decode: per-request write slots (continuous batching)
-            slots = jnp.mod(pos0, Smax) if window else pos0
-            bi = jnp.arange(B)
-            kc = cache["k"].at[bi, :, slots, :].set(km[:, :, 0, :])
-            vc = cache["v"].at[bi, :, slots, :].set(vm[:, :, 0, :])
-        elif S == 1:
-            start = jnp.mod(pos0, Smax) if window else pos0
-            kc = jax.lax.dynamic_update_slice(cache["k"], km, (0, 0, start, 0))
-            vc = jax.lax.dynamic_update_slice(cache["v"], vm, (0, 0, start, 0))
-        elif kv_extent:
-            # chunked prefill: commit this chunk's rows at pos0 (the engine
-            # guarantees pos0 + S <= Smax)
-            kc = jax.lax.dynamic_update_slice(cache["k"], km, (0, 0, pos0, 0))
-            vc = jax.lax.dynamic_update_slice(cache["v"], vm, (0, 0, pos0, 0))
-        elif S >= Smax:
-            # prefill larger than ring: keep the last Smax tokens, placed so
-            # that token at absolute position p sits at slot p % Smax
-            km, vm = km[:, :, -Smax:], vm[:, :, -Smax:]
-            shift = S % Smax
-            kc = jnp.roll(km, shift, axis=2)
-            vc = jnp.roll(vm, shift, axis=2)
-        else:
-            kc = jax.lax.dynamic_update_slice(cache["k"], km, (0, 0, 0, 0))
-            vc = jax.lax.dynamic_update_slice(cache["v"], vm, (0, 0, 0, 0))
-        new_cache = {"k": kc, "v": vc}
+        with jax.named_scope("kv_write"):
+            Smax = cache["k"].shape[2]
+            # (B, Kh, S, hd)
+            km = jnp.moveaxis(k, 1, 2).astype(cache["k"].dtype)
+            vm = jnp.moveaxis(v, 1, 2).astype(cache["v"].dtype)
+            pos_vec = jnp.asarray(pos0).ndim == 1
+            if S == 1 and pos_vec:
+                # ragged decode: per-request write slots (continuous
+                # batching)
+                slots = jnp.mod(pos0, Smax) if window else pos0
+                bi = jnp.arange(B)
+                kc = cache["k"].at[bi, :, slots, :].set(km[:, :, 0, :])
+                vc = cache["v"].at[bi, :, slots, :].set(vm[:, :, 0, :])
+            elif S == 1:
+                start = jnp.mod(pos0, Smax) if window else pos0
+                kc = jax.lax.dynamic_update_slice(cache["k"], km,
+                                                  (0, 0, start, 0))
+                vc = jax.lax.dynamic_update_slice(cache["v"], vm,
+                                                  (0, 0, start, 0))
+            elif kv_extent:
+                # chunked prefill: commit this chunk's rows at pos0 (the
+                # engine guarantees pos0 + S <= Smax)
+                kc = jax.lax.dynamic_update_slice(cache["k"], km,
+                                                  (0, 0, pos0, 0))
+                vc = jax.lax.dynamic_update_slice(cache["v"], vm,
+                                                  (0, 0, pos0, 0))
+            elif S >= Smax:
+                # prefill larger than ring: keep the last Smax tokens,
+                # placed so that token at absolute position p sits at slot
+                # p % Smax
+                km, vm = km[:, :, -Smax:], vm[:, :, -Smax:]
+                shift = S % Smax
+                kc = jnp.roll(km, shift, axis=2)
+                vc = jnp.roll(vm, shift, axis=2)
+            else:
+                kc = jax.lax.dynamic_update_slice(cache["k"], km,
+                                                  (0, 0, 0, 0))
+                vc = jax.lax.dynamic_update_slice(cache["v"], vm,
+                                                  (0, 0, 0, 0))
+            new_cache = {"k": kc, "v": vc}
 
     if S == 1 and cache is not None:
         out = decode_attention_jnp(q, new_cache["k"], new_cache["v"],
@@ -512,12 +528,13 @@ def apply_mla(cfg: ModelConfig, params: Params, x: jax.Array, *,
 
     new_cache = None
     if cache is not None:
-        lat = jax.lax.dynamic_update_slice(
-            cache["latent"], latent.astype(cache["latent"].dtype),
-            (0, pos0 if S == 1 else 0, 0))
-        krc = jax.lax.dynamic_update_slice(
-            cache["k_rope"], k_rope.astype(cache["k_rope"].dtype),
-            (0, pos0 if S == 1 else 0, 0))
+        with jax.named_scope("kv_write"):
+            lat = jax.lax.dynamic_update_slice(
+                cache["latent"], latent.astype(cache["latent"].dtype),
+                (0, pos0 if S == 1 else 0, 0))
+            krc = jax.lax.dynamic_update_slice(
+                cache["k_rope"], k_rope.astype(cache["k_rope"].dtype),
+                (0, pos0 if S == 1 else 0, 0))
         new_cache = {"latent": lat, "k_rope": krc}
 
     scale = 1.0 / math.sqrt(nd + rd)

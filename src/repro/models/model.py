@@ -25,6 +25,7 @@ from repro.models.kvcache import init_cache
 f32 = jnp.float32
 
 
+@jax.named_scope("embed")
 def embed_tokens(cfg: ModelConfig, params: dict, tokens: jax.Array,
                  pos0=0) -> jax.Array:
     x = params["embed"][tokens]
@@ -37,6 +38,7 @@ def embed_tokens(cfg: ModelConfig, params: dict, tokens: jax.Array,
     return x
 
 
+@jax.named_scope("head")
 def lm_head(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:
     h = L.rms_norm(params["final_norm"], x, cfg.rms_eps)
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

@@ -9,6 +9,7 @@ by mixers that don't use them.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from dataclasses import dataclass
@@ -78,6 +79,16 @@ def init_block(key, cfg: ModelConfig, kind: LayerKind, dtype=jnp.float32) -> dic
 # Per-layer apply
 # ---------------------------------------------------------------------------
 
+_ATTENTION_MIXERS = (MIXER_ATTN, MIXER_MLA, MIXER_CROSS)
+
+
+def _mixer_scope(mixer: str):
+    """``attention`` for the attention mixers; recurrent mixers (Mamba)
+    stay unscoped."""
+    return (jax.named_scope("attention") if mixer in _ATTENTION_MIXERS
+            else contextlib.nullcontext())
+
+
 def apply_block(cfg: ModelConfig, kind: LayerKind, params: dict, x: jax.Array,
                 ctx: BlockCtx):
     """Returns (x, new_cache, aux)."""
@@ -91,50 +102,57 @@ def apply_block(cfg: ModelConfig, kind: LayerKind, params: dict, x: jax.Array,
         mc = L.cast_like(mc, cache.get("mixer"))
         return x, ({"mixer": mc} if mc is not None else None), aux + a
 
-    h = L.rms_norm(params["ln1"], x, cfg.rms_eps)
     new_cache: dict = {}
-    if kind.mixer == MIXER_ATTN:
-        y, mc, a = L.apply_attention(
-            cfg, params["mixer"], h, pos0=ctx.pos0, cache=cache.get("mixer"),
-            is_global=ctx.is_global, causal=ctx.causal, tp_axis=ctx.tp_axis,
-            kv_block=ctx.kv_block,
-            sp_axis=ctx.sp_axis if ctx.is_global else None,
-            block_table=ctx.block_table, paged_kernel=ctx.paged_kernel,
-            kv_extent=ctx.kv_extent)
-    elif kind.mixer == MIXER_MLA:
-        y, mc, a = L.apply_mla(
-            cfg, params["mixer"], h, pos0=ctx.pos0, cache=cache.get("mixer"),
-            tp_axis=ctx.tp_axis, kv_block=ctx.kv_block)
-    elif kind.mixer == MIXER_CROSS:
-        y, mc, a = L.apply_cross_attention(
-            cfg, params["mixer"], h, memory=ctx.memory,
-            cache=cache.get("mixer"), tp_axis=ctx.tp_axis)
-    elif kind.mixer == MIXER_MAMBA:
-        y, mc, a = S.apply_mamba(cfg, params["mixer"], h,
-                                 cache=cache.get("mixer"), tp_axis=ctx.tp_axis)
-    else:
-        raise ValueError(kind.mixer)
-    x = x + y
-    aux += a
-    if mc is not None:
-        new_cache["mixer"] = L.cast_like(mc, cache.get("mixer"))
+    # named scopes (op_name metadata of every op, no change to the math)
+    # let a profile charge device time to the attention and the MLP
+    with _mixer_scope(kind.mixer):
+        h = L.rms_norm(params["ln1"], x, cfg.rms_eps)
+        if kind.mixer == MIXER_ATTN:
+            y, mc, a = L.apply_attention(
+                cfg, params["mixer"], h, pos0=ctx.pos0,
+                cache=cache.get("mixer"), is_global=ctx.is_global,
+                causal=ctx.causal, tp_axis=ctx.tp_axis, kv_block=ctx.kv_block,
+                sp_axis=ctx.sp_axis if ctx.is_global else None,
+                block_table=ctx.block_table, paged_kernel=ctx.paged_kernel,
+                kv_extent=ctx.kv_extent)
+        elif kind.mixer == MIXER_MLA:
+            y, mc, a = L.apply_mla(
+                cfg, params["mixer"], h, pos0=ctx.pos0,
+                cache=cache.get("mixer"), tp_axis=ctx.tp_axis,
+                kv_block=ctx.kv_block)
+        elif kind.mixer == MIXER_CROSS:
+            y, mc, a = L.apply_cross_attention(
+                cfg, params["mixer"], h, memory=ctx.memory,
+                cache=cache.get("mixer"), tp_axis=ctx.tp_axis)
+        elif kind.mixer == MIXER_MAMBA:
+            y, mc, a = S.apply_mamba(cfg, params["mixer"], h,
+                                     cache=cache.get("mixer"),
+                                     tp_axis=ctx.tp_axis)
+        else:
+            raise ValueError(kind.mixer)
+        x = x + y
+        aux += a
+        if mc is not None:
+            new_cache["mixer"] = L.cast_like(mc, cache.get("mixer"))
 
     if kind.extra_cross:
-        h = L.rms_norm(params["ln_cross"], x, cfg.rms_eps)
-        y, cc, _ = L.apply_cross_attention(
-            cfg, params["cross"], h, memory=ctx.memory,
-            cache=cache.get("cross"), tp_axis=ctx.tp_axis)
-        x = x + y
-        if cc is not None:
-            new_cache["cross"] = L.cast_like(cc, cache.get("cross"))
+        with jax.named_scope("attention"):
+            h = L.rms_norm(params["ln_cross"], x, cfg.rms_eps)
+            y, cc, _ = L.apply_cross_attention(
+                cfg, params["cross"], h, memory=ctx.memory,
+                cache=cache.get("cross"), tp_axis=ctx.tp_axis)
+            x = x + y
+            if cc is not None:
+                new_cache["cross"] = L.cast_like(cc, cache.get("cross"))
 
-    h = L.rms_norm(params["ln2"], x, cfg.rms_eps)
-    if kind.mlp == MLP_MOE:
-        y, _, a = L.apply_moe(cfg, params["mlp"], h, tp_axis=ctx.tp_axis)
-    else:
-        y, _, a = L.apply_mlp(cfg, params["mlp"], h, tp_axis=ctx.tp_axis)
-    x = x + y
-    aux += a
+    with jax.named_scope("mlp"):
+        h = L.rms_norm(params["ln2"], x, cfg.rms_eps)
+        if kind.mlp == MLP_MOE:
+            y, _, a = L.apply_moe(cfg, params["mlp"], h, tp_axis=ctx.tp_axis)
+        else:
+            y, _, a = L.apply_mlp(cfg, params["mlp"], h, tp_axis=ctx.tp_axis)
+        x = x + y
+        aux += a
     return x, (new_cache or None), aux
 
 

@@ -68,6 +68,7 @@ from repro.serving.executor_cache import ExecutorCache, trace_count
 from repro.serving.faults import (COMM_TRANSIENT, OOM, PREEMPT_STAGE,
                                   SLOWDOWN)
 from repro.serving.metrics import ServingStats
+from repro.serving.tracing import span
 from repro.serving.workload import Request
 
 
@@ -294,6 +295,10 @@ class FlexPipeEngine:
         # canonical state: per-layer cache list (dense: batch rows; paged:
         # block pools shared across the batch)
         self.caches = self._init_caches()
+        # KV rows the cache holds, live or not (the kv_cache_rows counter)
+        self._cache_rows = (
+            self.ecfg.n_blocks * self.ecfg.block_size if self.ecfg.paged
+            else self.ecfg.max_batch * self.ecfg.max_seq)
         self.slots = [Slot() for _ in range(self.ecfg.max_batch)]
         # overload protection: with an AdmissionConfig the queue IS the
         # bounded EDF AdmissionQueue (list-compatible for len/append);
@@ -448,36 +453,37 @@ class FlexPipeEngine:
         cache — a hit costs a dict lookup, a miss compiles eagerly here
         (reported via ``compile_cache_hit`` / ``new_traces``) so the decode
         loop never stalls on XLA mid-stream."""
-        t0 = time.perf_counter()
-        old = list(self.boundaries)
-        traces0 = trace_count()
-        self.boundaries = list(new_boundaries)
-        hit = True
-        if self.ecfg.fused_decode:
-            self._fused, registered = self.executors.fused_decode(
-                tuple(self.boundaries))
-            # a program registered but never executed still owes its jit
-            # trace+compile: pay it here, not on the next decode tick, and
-            # report the hit only when it was genuinely compiled already
-            hit = registered and self._fused.compiled
-            if not self._fused.compiled:
-                self._compile_fused(self._fused)
-        else:
-            missed = []
-            for lo, hi in self._stage_ranges():
-                fn, h = self.executors.stage_decode(lo, hi)
-                hit = hit and h
-                if not h:
-                    missed.append((lo, hi, fn))
-            if missed:
-                self._compile_stages(missed)
-        ev = {"t": time.perf_counter() - t0, "from": old,
-              "to": list(new_boundaries),
-              "inflight": sum(1 for s in self.slots if not s.done),
-              "compile_cache_hit": hit,
-              "new_traces": trace_count() - traces0}
-        self.refactor_events.append(ev)
-        return ev
+        with span("engine.refactor"):
+            t0 = time.perf_counter()
+            old = list(self.boundaries)
+            traces0 = trace_count()
+            self.boundaries = list(new_boundaries)
+            hit = True
+            if self.ecfg.fused_decode:
+                self._fused, registered = self.executors.fused_decode(
+                    tuple(self.boundaries))
+                # a program registered but never executed still owes its jit
+                # trace+compile: pay it here, not on the next decode tick, and
+                # report the hit only when it was genuinely compiled already
+                hit = registered and self._fused.compiled
+                if not self._fused.compiled:
+                    self._compile_fused(self._fused)
+            else:
+                missed = []
+                for lo, hi in self._stage_ranges():
+                    fn, h = self.executors.stage_decode(lo, hi)
+                    hit = hit and h
+                    if not h:
+                        missed.append((lo, hi, fn))
+                if missed:
+                    self._compile_stages(missed)
+            ev = {"t": time.perf_counter() - t0, "from": old,
+                  "to": list(new_boundaries),
+                  "inflight": sum(1 for s in self.slots if not s.done),
+                  "compile_cache_hit": hit,
+                  "new_traces": trace_count() - traces0}
+            self.refactor_events.append(ev)
+            return ev
 
     def _compile_fused(self, prog) -> None:
         """Force trace+compile off the decode stream via a throwaway tick on
@@ -1012,7 +1018,8 @@ class FlexPipeEngine:
         spent = 0
         while ring and spent < budget:
             i = ring.pop(0)
-            spent += self._prefill_chunk_into(i, now)
+            with span("engine.prefill_chunk", rid=self.slots[i].request.rid):
+                spent += self._prefill_chunk_into(i, now)
             s = self.slots[i]
             if not s.done and not s.generated:
                 ring.append(i)         # more chunks pending: back of line
@@ -1054,8 +1061,9 @@ class FlexPipeEngine:
         if final:
             # only the final chunk samples; its one token must reach the
             # host to seed s.generated for the decode loop
-            # repro: noqa[JIT102] -- intended one-token sync (last chunk)
-            first = int(np.asarray(out)[0])          # first sampled token
+            with span("engine.sync"):
+                # repro: noqa[JIT102] -- intended one-token sync (last chunk)
+                first = int(np.asarray(out)[0])      # first sampled token
             req.first_token = now                    # TTFT: this chunk
             s.generated = [first]
             eos = self.ecfg.eos_token
@@ -1073,52 +1081,54 @@ class FlexPipeEngine:
                            now: float = 0.0) -> None:
         prompt, budget = self._truncate_prompt(req)
         S = int(prompt.shape[0])
-        if self.ecfg.paged:
-            # blocks for the prompt + the first decode write; bucket
-            # padding beyond them scatters into the null block
-            if not self._alloc_for_slot(
-                    slot_id, blocks_for(S + 1, self.ecfg.block_size)):
-                req.enqueued_at = now       # pool raced empty: requeue
-                req.retry_at = now
-                self.queue.append(req)
-                return
         Sp = self.executors.prefill_bucket(S)
-        toks = np.zeros((1, Sp), np.int32)
-        toks[0, :S] = prompt
-        memory = getattr(req, "memory", None)
-        ranges = self._stage_ranges()
-        out = jnp.asarray(toks)
-        slot_ix = (jnp.asarray(self.block_tables[slot_id:slot_id + 1])
-                   if self.ecfg.paged else jnp.asarray(slot_id, jnp.int32))
-        true_len = jnp.asarray(S, jnp.int32)
-        for si, (lo, hi) in enumerate(ranges):
-            fn, _ = self.executors.stage_prefill(
-                lo, hi, first=(si == 0), last=(si == len(ranges) - 1))
-            out, new = fn(self.params["blocks"][lo:hi],
-                          self.executors.head_params, out,
-                          self.caches[lo:hi], slot_ix, true_len, memory)
-            self.caches[lo:hi] = new
-        slot = self.slots[slot_id]
-        slot.request = req
-        slot.pos = S
-        slot.prompt = prompt.astype(np.int64)
-        slot.budget = budget
-        # repro: noqa[JIT102] -- intended one-token sync ending prefill
-        first = int(np.asarray(out)[0])              # first sampled token
-        req.first_token = now                        # TTFT: prefill emits it
-        slot.generated = [first]
-        slot.done = False
-        eos = self.ecfg.eos_token
-        if budget <= 1 or (eos >= 0 and first == eos):
-            # budget already exhausted by the prefill's token: finish now
-            # rather than letting the next tick overshoot max_new_tokens
-            req.finish = now
-            self.stats.record(now, req.latency, req.met_slo,
-                              queue_s=req.queue_wait,
-                              ttft_s=req.first_token - req.arrival)
-            slot.done = True
-            slot.request = None
-            self._free_slot_blocks(slot_id)
+        with span("engine.prefill", rid=req.rid, bucket=Sp):
+            if self.ecfg.paged:
+                # blocks for the prompt + the first decode write; bucket
+                # padding beyond them scatters into the null block
+                if not self._alloc_for_slot(
+                        slot_id, blocks_for(S + 1, self.ecfg.block_size)):
+                    req.enqueued_at = now       # pool raced empty: requeue
+                    req.retry_at = now
+                    self.queue.append(req)
+                    return
+            toks = np.zeros((1, Sp), np.int32)
+            toks[0, :S] = prompt
+            memory = getattr(req, "memory", None)
+            ranges = self._stage_ranges()
+            out = jnp.asarray(toks)
+            slot_ix = (jnp.asarray(self.block_tables[slot_id:slot_id + 1])
+                       if self.ecfg.paged else jnp.asarray(slot_id, jnp.int32))
+            true_len = jnp.asarray(S, jnp.int32)
+            for si, (lo, hi) in enumerate(ranges):
+                fn, _ = self.executors.stage_prefill(
+                    lo, hi, first=(si == 0), last=(si == len(ranges) - 1))
+                out, new = fn(self.params["blocks"][lo:hi],
+                              self.executors.head_params, out,
+                              self.caches[lo:hi], slot_ix, true_len, memory)
+                self.caches[lo:hi] = new
+            slot = self.slots[slot_id]
+            slot.request = req
+            slot.pos = S
+            slot.prompt = prompt.astype(np.int64)
+            slot.budget = budget
+            with span("engine.sync"):
+                # repro: noqa[JIT102] -- intended one-token sync ending prefill
+                first = int(np.asarray(out)[0])      # first sampled token
+            req.first_token = now                    # TTFT: prefill emits it
+            slot.generated = [first]
+            slot.done = False
+            eos = self.ecfg.eos_token
+            if budget <= 1 or (eos >= 0 and first == eos):
+                # budget already exhausted by the prefill's token: finish now
+                # rather than letting the next tick overshoot max_new_tokens
+                req.finish = now
+                self.stats.record(now, req.latency, req.met_slo,
+                                  queue_s=req.queue_wait,
+                                  ttft_s=req.first_token - req.arrival)
+                slot.done = True
+                slot.request = None
+                self._free_slot_blocks(slot_id)
 
     # ------------------------------------------------------------------
     def decode_step(self, now: float) -> int:
@@ -1126,67 +1136,79 @@ class FlexPipeEngine:
 
         Fused path: one XLA dispatch for embed + all stages + lm_head +
         argmax; the engine's caches are donated and replaced by the tick's
-        outputs, and only B int32 token ids come back to host."""
+        outputs, and only B int32 token ids come back to host.  Each tick
+        bumps the KV row counters: ``decode_ticks``, ``kv_live_rows`` (rows
+        the decoding slots attend over, their new row included) and
+        ``kv_cache_rows`` (rows the cache holds, live or not)."""
         B = self.ecfg.max_batch
-        if self.ecfg.paged:
-            # tail-block growth happens BEFORE the active mask is read:
-            # a slot the pool can't grow is preempted and skips this tick
-            self._ensure_decode_blocks(now)
-        # mid-prefill slots (chunked: no sampled token yet) don't decode
-        active = np.array([not s.done and len(s.generated) > 0
-                           for s in self.slots])
-        n_active = int(active.sum())
-        if not n_active:
-            return 0
-        tok = np.zeros((B, 1), np.int32)
-        pos = np.zeros((B,), np.int32)
-        if self._chunk:
-            # the fused tick writes a KV row for EVERY batch slot; park a
-            # mid-prefill slot's garbage write on its next chunk's first
-            # row (pos), which that chunk overwrites — never on row 0,
-            # where it would clobber the slot's committed chunk 0
-            for i, s in enumerate(self.slots):
-                if not s.done and not s.generated:
-                    pos[i] = s.pos
-        for i in np.nonzero(active)[0]:
-            s = self.slots[i]
-            tok[i, 0] = s.generated[-1]
-            pos[i] = s.pos
+        with span("engine.decode.prepare"):
+            if self.ecfg.paged:
+                # tail-block growth happens BEFORE the active mask is read:
+                # a slot the pool can't grow is preempted and skips this tick
+                self._ensure_decode_blocks(now)
+            live = np.array([not s.done for s in self.slots])
+            gen = np.array([len(s.generated) for s in self.slots])
+            # mid-prefill slots (chunked: no sampled token yet) don't decode
+            active = live & (gen > 0)
+            n_active = int(active.sum())
+            if not n_active:
+                return 0
+            tok = np.zeros((B, 1), np.int32)
+            pos = np.zeros((B,), np.int32)
+            if self._chunk:
+                # the fused tick writes a KV row for EVERY batch slot; park a
+                # mid-prefill slot's garbage write on its next chunk's first
+                # row (pos), which that chunk overwrites — never on row 0,
+                # where it would clobber the slot's committed chunk 0
+                for i in np.nonzero(live & ~active)[0]:
+                    pos[i] = self.slots[i].pos
+            for i in np.nonzero(active)[0]:
+                s = self.slots[i]
+                tok[i, 0] = s.generated[-1]
+                pos[i] = s.pos
+            if self._fused is not None:
+                args = (jnp.asarray(tok), jnp.asarray(pos), self._tables_dev())
         if self._fused is not None:
-            nxt_dev, new = self._fused.step(self.caches, jnp.asarray(tok),
-                                            jnp.asarray(pos),
-                                            self._tables_dev())
-            self.caches = new
-            # repro: noqa[JIT102] -- THE per-tick sync: one B-int32 copy
-            nxt = np.asarray(nxt_dev)
+            with span("engine.decode.dispatch"):
+                nxt_dev, new = self._fused.step(self.caches, *args)
+                self.caches = new
+            with span("engine.sync"):
+                # repro: noqa[JIT102] -- THE per-tick sync: one B-int32 copy
+                nxt = np.asarray(nxt_dev)
         else:
-            nxt = self._decode_unfused(tok, pos)
-        # EOS / length bookkeeping, vectorized in numpy
-        gen = np.array([len(s.generated) for s in self.slots])
-        lim = np.array([s.budget if s.request else 0 for s in self.slots])
-        eos = self.ecfg.eos_token
-        hit_eos = (eos >= 0) & (nxt == eos)
-        finished = active & ((gen + 1 >= lim) | hit_eos)
-        for i in np.nonzero(active)[0]:
-            s = self.slots[i]
-            s.generated.append(int(nxt[i]))
-            s.pos += 1
-        for i in np.nonzero(finished)[0]:
-            s = self.slots[i]
-            req = s.request
-            req.finish = now
-            self.stats.record(now, req.latency, req.met_slo,
-                              queue_s=req.queue_wait,
-                              ttft_s=req.first_token - req.arrival)
-            s.done = True
-            s.request = None
-            self._free_slot_blocks(i)
-        if self.ecfg.paged:
-            bsst = self.block_stats()
-            self.stats.record_blocks(now, bsst["used_blocks"],
-                                     bsst["free_blocks"],
-                                     bsst["fragmentation"])
-        self._maybe_snapshot()
+            with span("engine.decode.dispatch"):
+                nxt = self._decode_unfused(tok, pos)
+        with span("engine.decode.bookkeep"):
+            # EOS / length bookkeeping, vectorized in numpy
+            lim = np.array([s.budget if s.request else 0 for s in self.slots])
+            eos = self.ecfg.eos_token
+            hit_eos = (eos >= 0) & (nxt == eos)
+            finished = active & ((gen + 1 >= lim) | hit_eos)
+            for i in np.nonzero(active)[0]:
+                s = self.slots[i]
+                s.generated.append(int(nxt[i]))
+                s.pos += 1
+            for i in np.nonzero(finished)[0]:
+                s = self.slots[i]
+                req = s.request
+                req.finish = now
+                self.stats.record(now, req.latency, req.met_slo,
+                                  queue_s=req.queue_wait,
+                                  ttft_s=req.first_token - req.arrival)
+                s.done = True
+                s.request = None
+                self._free_slot_blocks(i)
+            rows = pos + active              # rows each slot holds after it
+            self.stats.bump("decode_ticks")
+            self.stats.bump("kv_live_rows", int(rows[active].sum()))
+            self.stats.bump("kv_cache_rows", self._cache_rows)
+            if self.ecfg.paged:
+                used = self.allocator.n_used
+                self.stats.record_blocks(
+                    used, self.allocator.n_free,
+                    fragmentation(int(rows[live & ~finished].sum()), used,
+                                  self.ecfg.block_size))
+            self._maybe_snapshot()
         return n_active
 
     def _decode_unfused(self, tok: np.ndarray, pos: np.ndarray) -> np.ndarray:
@@ -1213,26 +1235,35 @@ class FlexPipeEngine:
         This is the typed driver the benchmarks and ``run()`` use; manual
         loops that only need decode can keep calling ``decode_step``
         (whole-prompt prefill still happens inside ``_admit``)."""
-        completed0 = self.stats.completed
-        self._apply_fault_policy(now)
-        if self.admission is not None:
-            # shed already-dead queued work even while slots are full,
-            # then advance the brownout controller on saturation
-            self.admission.expire(now)
-            self.admission.update(now)
-        admitted = self._admit(now)
-        recs = self.fault_step(now)
-        prefill_tokens = self._prefill_step(now)
-        t_tick = time.perf_counter()
-        decoded = self.decode_step(now)
-        self.health_step(now, time.perf_counter() - t_tick)
-        return TickReport(
-            now=now, decoded=decoded, prefill_tokens=prefill_tokens,
-            prefilling=sum(1 for s in self.slots
-                           if not s.done and not s.generated),
-            admitted=admitted,
-            completed=self.stats.completed - completed0,
-            queue_depth=len(self.queue), recoveries=len(recs))
+        with span("engine.step"):
+            completed0 = self.stats.completed
+            with span("engine.faults"):
+                self._apply_fault_policy(now)
+            with span("engine.admit"):
+                if self.admission is not None:
+                    # shed already-dead queued work even while slots are
+                    # full, then advance the brownout controller on
+                    # saturation
+                    self.admission.expire(now)
+                    self.admission.update(now)
+                admitted = self._admit(now)
+            with span("engine.faults"):
+                recs = self.fault_step(now)
+            prefill_tokens = self._prefill_step(now)
+            if self.health is None:
+                decoded = self.decode_step(now)
+            else:
+                t_tick = time.perf_counter()
+                decoded = self.decode_step(now)
+                with span("engine.faults"):
+                    self.health_step(now, time.perf_counter() - t_tick)
+            return TickReport(
+                now=now, decoded=decoded, prefill_tokens=prefill_tokens,
+                prefilling=sum(1 for s in self.slots
+                               if not s.done and not s.generated),
+                admitted=admitted,
+                completed=self.stats.completed - completed0,
+                queue_depth=len(self.queue), recoveries=len(recs))
 
     def run(self, requests: list[Request], controller=None,
             time_per_tick: float = 0.05) -> ServingStats:

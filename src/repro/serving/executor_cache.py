@@ -130,8 +130,10 @@ def _fused_decode_fn(cfg: ModelConfig, boundaries: tuple[int, ...],
                 x, stk_new = jax.lax.scan(body, x, (rp, stk))
                 for j, li in enumerate(range(lo, hi)):
                     new[li] = jax.tree.map(lambda l, _j=j: l[_j], stk_new)
-        logits = lm_head(cfg, extras, x)[:, -1, :]
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), tuple(new)
+        logits = lm_head(cfg, extras, x)
+        with jax.named_scope("head"):        # the token pick is the head's
+            nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+        return nxt, tuple(new)
 
     if paged:
         def tick(extras, caches, run_params, tok, pos, block_tables):

@@ -35,8 +35,11 @@ class ServingStats:
     # overload accounting (serving/admission.py)
     ttfts: list = field(default_factory=list)          # time-to-first-token
     saturation_samples: list = field(default_factory=list)  # (t, sat 0..1)
-    # paged-KV accounting: (t, used_blocks, free_blocks, fragmentation 0..1)
-    block_samples: list = field(default_factory=list)
+    # paged-KV accounting over paged decode ticks, in fixed memory: the
+    # tick count, summed used blocks and fragmentation, and the extremes
+    block_tally: dict = field(default_factory=lambda: {
+        "ticks": 0, "used": 0, "max_used": 0, "min_free": 0, "frag": 0.0,
+        "max_frag": 0.0})
 
     def record(self, finish_t: float, latency: float, met_slo: bool,
                queue_s: float = 0.0, compute_s: float = 0.0,
@@ -70,26 +73,41 @@ class ServingStats:
     def record_saturation(self, t: float, sat: float) -> None:
         self.saturation_samples.append((t, sat))
 
-    def record_blocks(self, t: float, used: int, free: int,
-                      frag: float) -> None:
-        """Block-pool occupancy sample: used/free physical blocks and
-        internal fragmentation (allocated-but-dead token slots in tail
-        blocks / allocated capacity)."""
-        self.block_samples.append((t, used, free, frag))
+    def record_blocks(self, used: int, free: int, frag: float) -> None:
+        """One paged tick's block-pool occupancy: used/free physical blocks
+        and internal fragmentation (allocated-but-dead token slots in tail
+        blocks / allocated capacity), folded into ``block_tally``."""
+        b = self.block_tally
+        b["min_free"] = min(b["min_free"], free) if b["ticks"] else free
+        b["ticks"] += 1
+        b["used"] += used
+        b["max_used"] = max(b["max_used"], used)
+        b["frag"] += frag
+        b["max_frag"] = max(b["max_frag"], frag)
 
     def block_summary(self) -> dict:
         """Real KV footprint next to the slot-fraction watermark signal."""
-        if not self.block_samples:
+        b = self.block_tally
+        n = b["ticks"]
+        if not n:
             return {"mean_used": 0.0, "max_used": 0, "min_free": 0,
                     "mean_frag": 0.0, "max_frag": 0.0}
-        used = [u for _, u, _, _ in self.block_samples]
-        free = [f for _, _, f, _ in self.block_samples]
-        frag = [g for _, _, _, g in self.block_samples]
-        return {"mean_used": float(np.mean(used)),
-                "max_used": int(np.max(used)),
-                "min_free": int(np.min(free)),
-                "mean_frag": float(np.mean(frag)),
-                "max_frag": float(np.max(frag))}
+        return {"mean_used": b["used"] / n, "max_used": b["max_used"],
+                "min_free": b["min_free"], "mean_frag": b["frag"] / n,
+                "max_frag": b["max_frag"]}
+
+    def kv_summary(self) -> dict:
+        """KV rows the decode ticks attended over against the rows the
+        cache holds (the ``kv_live_rows`` / ``kv_cache_rows`` counters): a
+        low ``live_share`` says the cache is sized far above the traffic's
+        live context, and that dense decode attention reads mostly dead
+        rows."""
+        c = self.counters
+        n, held = c.get("decode_ticks", 0), c.get("kv_cache_rows", 0)
+        live = c.get("kv_live_rows", 0)
+        return {"decode_ticks": n,
+                "mean_live_rows": live / n if n else 0.0,
+                "live_share": live / held if held else 0.0}
 
     def saturation_summary(self) -> dict:
         if not self.saturation_samples:

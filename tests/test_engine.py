@@ -9,7 +9,8 @@ from repro.configs.base import get_arch
 from repro.core.refactoring import merge_with_mask, snapshot
 from repro.models.kvcache import init_cache, migration_plan
 from repro.models.transformer import init_model
-from repro.serving.engine import EngineConfig, FlexPipeEngine
+from repro.serving.engine import (EngineConfig, FlexPipeEngine,
+                                  KVCacheConfig)
 from repro.serving.workload import Request
 
 
@@ -37,6 +38,37 @@ def _run(boundaries, refactor_at=None, new_boundaries=None, steps=10):
             if s.generated:
                 hist[i] = list(s.generated)
     return hist, eng
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_kv_row_counters_match_the_slots(paged):
+    """Each decode tick adds the rows its decoding slots attend over (their
+    cache length after the tick's write) to ``kv_live_rows`` and the rows
+    the cache holds to ``kv_cache_rows``."""
+    eng = FlexPipeEngine(CFG, PARAMS, [0, 2], EngineConfig(
+        max_batch=4, max_seq=64, kv=KVCacheConfig(paged=paged,
+                                                  block_size=8)))
+    held = eng.ecfg.n_blocks * 8 if paged else 4 * 64
+    for r in _reqs(tokens=5):
+        eng.submit(r)
+    eng._admit(0.0)
+    c = eng.stats.counters
+    live = cache = 0
+    for t in range(7):                   # the requests finish at tick 4
+        decoding = [i for i, s in enumerate(eng.slots)
+                    if not s.done and s.generated]
+        n = eng.decode_step(t * 0.1)
+        assert n == len(decoding)
+        if n:
+            live += sum(eng.slots[i].pos for i in decoding)
+            cache += held
+        assert c.get("decode_ticks", 0) == min(t + 1, 4)
+        assert c.get("kv_live_rows", 0) == live
+        assert c.get("kv_cache_rows", 0) == cache
+    assert live == sum(p + k for p in (12, 13, 14) for k in range(1, 5))
+    assert eng.stats.kv_summary() == {
+        "decode_ticks": 4, "mean_live_rows": live / 4,
+        "live_share": live / cache}
 
 
 class TestInflightRefactoring:

@@ -2,6 +2,8 @@
 regroup must not copy, fused and unfused paths must agree bit-exactly, and
 stage programs must be shared across configurations that cut the model at
 the same layer."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +11,8 @@ import pytest
 
 from repro.configs.base import get_arch
 from repro.models.transformer import init_model, scan_runs, stack_blocks
-from repro.serving.engine import EngineConfig, FlexPipeEngine
+from repro.serving.engine import (EngineConfig, FlexPipeEngine,
+                                  KVCacheConfig)
 from repro.serving.workload import Request
 
 
@@ -223,6 +226,50 @@ class TestEngineConfigHygiene:
         eng._admit(0.0)
         assert eng.decode_step(0.0) == 2
         assert all(l.dtype == jnp.bfloat16 for l in jax.tree.leaves(eng.caches))
+
+
+SCOPES = ("embed", "attention", "kv_write", "mlp", "head")
+
+
+def _entry_ops(hlo: str) -> list:
+    """(opcode, op_name) of every instruction of the compiled program's
+    entry computation."""
+    ops = []
+    for line in hlo[hlo.index("\nENTRY"):].splitlines()[1:]:
+        m = re.search(r" = .*? ([a-z][a-z0-9_-]*)\(", line)
+        if m:
+            name = re.search(r'op_name="([^"]*)"', line)
+            ops.append((m.group(1), name.group(1) if name else ""))
+    return ops
+
+
+def _scope(op_name: str):
+    """The innermost of the known scopes in an op's name path (a fused op
+    joins its parts' paths with ';': the first decides)."""
+    path = op_name.split(";")[0].split("/")
+    return next((p for p in reversed(path) if p in SCOPES), None)
+
+
+@pytest.mark.parametrize("paged,kernel", [(False, False), (True, False),
+                                          (True, True)])
+def test_tick_ops_carry_named_scopes(paged, kernel):
+    """Every op the fused tick's code makes (op_name under ``jit(tick)/``)
+    lies in one of the five scopes, and each scope has ops.  Ops that XLA
+    adds itself, such as a layout copy of a cache parameter, carry the
+    parameter's name or none, and a profile counts them as unscoped."""
+    eng = _engine([0, 2], kv=KVCacheConfig(paged=paged, block_size=8,
+                                          paged_kernel=kernel))
+    B = eng.ecfg.max_batch
+    tables = jnp.zeros((B, eng._max_blocks), jnp.int32) if paged else None
+    hlo = eng._fused.lower(eng.caches, jnp.zeros((B, 1), jnp.int32),
+                           jnp.zeros((B,), jnp.int32),
+                           tables).compile().as_text()
+    ops = _entry_ops(hlo)
+    traced = [(op, n) for op, n in ops if n.startswith("jit(tick)/")]
+    assert all(n.startswith("jit(tick)/") for op, n in ops if op == "dot")
+    unscoped = [(op, n) for op, n in traced if _scope(n) is None]
+    assert unscoped == []
+    assert {_scope(n) for _, n in traced} == set(SCOPES)
 
 
 class TestScanRuns:

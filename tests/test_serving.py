@@ -145,3 +145,23 @@ class TestMetrics:
         s.record(1.0, 0.5, True)
         s.record(2.0, 9.0, False)
         assert s.goodput(10.0) == pytest.approx(0.1)
+
+    def test_block_tally_summarises_every_tick(self):
+        """The fixed-memory tally reads what the per-tick samples it
+        replaced read: means over ticks and the extremes."""
+        rng = np.random.default_rng(0)
+        used = rng.integers(0, 100, 50)
+        free = 100 - used
+        frag = rng.random(50)
+        s = ServingStats()
+        assert s.block_summary() == {"mean_used": 0.0, "max_used": 0,
+                                     "min_free": 0, "mean_frag": 0.0,
+                                     "max_frag": 0.0}
+        for u, f, g in zip(used, free, frag):
+            s.record_blocks(int(u), int(f), float(g))
+        got = s.block_summary()
+        assert got == pytest.approx({
+            "mean_used": used.mean(), "max_used": used.max(),
+            "min_free": free.min(), "mean_frag": frag.mean(),
+            "max_frag": frag.max()})
+        assert s.overload_summary()["blocks"] == got
