@@ -199,6 +199,29 @@ def decode_attention_jnp(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
+def _write_kv_rows(leaf: jax.Array, idx: jax.Array, offs: jax.Array,
+                   new: jax.Array) -> jax.Array:
+    """Write one row per (entry, KV head) into a head-major cache leaf.
+
+    leaf: ``(A, Kh, L, hd)``, a dense cache (A = batch, L = max_seq) or a
+    block pool (A = blocks, L = block size); idx, offs: ``(n,)`` leading
+    index and row of each entry; new: ``(n, Kh, hd)``.  The leaf is viewed
+    as ``(A * Kh, L, hd)`` (merging the two major dims is a bitcast) and
+    written with one ``(row, offset)`` index per entry and head, so the
+    scatter's window is ``hd`` alone.  ``leaf.at[idx, :, offs, :]`` writes
+    the same values, but its ``(Kh, hd)`` window straddles the indexed
+    dim, and on a TPU XLA then copies the whole leaf into another layout
+    and back on every call.  An entry whose offset lies past ``L`` is
+    dropped, as there.
+    """
+    A, Kh, L, hd = leaf.shape
+    rows = (idx[:, None] * Kh + jnp.arange(Kh)).reshape(-1)
+    offs = jnp.broadcast_to(offs[:, None], (idx.shape[0], Kh)).reshape(-1)
+    flat = leaf.reshape(A * Kh, L, hd).at[rows, offs].set(
+        new.reshape(-1, hd).astype(leaf.dtype))
+    return flat.reshape(leaf.shape)
+
+
 def _paged_attention(q, k, v, cache, block_table, *, pos0, wo, kv_block,
                      causal, paged_kernel, kv_extent=0):
     """Attention over the paged layout: pools + per-slot block tables.
@@ -232,8 +255,11 @@ def _paged_attention(q, k, v, cache, block_table, *, pos0, wo, kv_block,
         with jax.named_scope("kv_write"):
             pid = bt[jnp.arange(B), p0 // bs]
             off = p0 % bs
-            kc = cache["k"].at[pid, :, off, :].set(km[:, :, 0, :])
-            vc = cache["v"].at[pid, :, off, :].set(vm[:, :, 0, :])
+            # idle slots all write into the null block 0: which of the
+            # duplicate rows lands there is unspecified and harmless, since
+            # no masked read ever sees block 0
+            kc = _write_kv_rows(cache["k"], pid, off, km[:, :, 0, :])
+            vc = _write_kv_rows(cache["v"], pid, off, vm[:, :, 0, :])
         if paged_kernel:
             out = paged_decode_attention(q[:, 0], kc, vc, bt,
                                          p0 + 1)[:, None]
@@ -250,10 +276,10 @@ def _paged_attention(q, k, v, cache, block_table, *, pos0, wo, kv_block,
         with jax.named_scope("kv_write"):
             pids = bt[0, pos // bs]
             offs = pos % bs
-            kc = cache["k"].at[pids, :, offs, :].set(
-                jnp.moveaxis(km[0], 0, 1))
-            vc = cache["v"].at[pids, :, offs, :].set(
-                jnp.moveaxis(vm[0], 0, 1))
+            kc = _write_kv_rows(cache["k"], pids, offs,
+                                jnp.moveaxis(km[0], 0, 1))
+            vc = _write_kv_rows(cache["v"], pids, offs,
+                                jnp.moveaxis(vm[0], 0, 1))
         if kv_extent:
             # chunked prefill: attend over the slot's logical view so this
             # chunk's queries see all previously committed chunks
@@ -339,8 +365,8 @@ def apply_attention(cfg: ModelConfig, params: Params, x: jax.Array, *,
                 # batching)
                 slots = jnp.mod(pos0, Smax) if window else pos0
                 bi = jnp.arange(B)
-                kc = cache["k"].at[bi, :, slots, :].set(km[:, :, 0, :])
-                vc = cache["v"].at[bi, :, slots, :].set(vm[:, :, 0, :])
+                kc = _write_kv_rows(cache["k"], bi, slots, km[:, :, 0, :])
+                vc = _write_kv_rows(cache["v"], bi, slots, vm[:, :, 0, :])
             elif S == 1:
                 start = jnp.mod(pos0, Smax) if window else pos0
                 kc = jax.lax.dynamic_update_slice(cache["k"], km,
